@@ -71,6 +71,7 @@ for sync in ("dsgd", "two_phase", "hierarchical", "faithful"):
 def _collective_rows() -> list[str]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"  # fake host devices; never contend for a chip
     env["PYTHONPATH"] = str(ROOT / "src") + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(_COUNT_DEMO)],
                        capture_output=True, text=True, timeout=1200, env=env)

@@ -65,9 +65,9 @@ def init_telemetry(n_buckets: int) -> TelemetryState:
 def _stats_jnp(g: jax.Array):
     """Vectorized single-pass jnp fallback for the fused kernel.
 
-    Scatter-add histogram instead of the kernel's one-hot matmul
-    (``kernels.ref.bucket_stats_scatter``): safe under shard_map on the
-    pinned toolchain and O(n).  Counts/max are identical to the kernel;
+    Scatter-add histogram instead of the kernel's per-bin masked sums
+    (``kernels.ref.bucket_stats_scatter``): safe under shard_map and
+    O(n).  Counts/max are identical to the kernel;
     float sums may differ in the last bits (reduction order), which the EMA
     telemetry does not care about — the bit-exact contract is pinned
     between ``kernels.ops.bucket_stats`` and ``kernels.ref``.

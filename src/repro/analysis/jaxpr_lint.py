@@ -37,6 +37,7 @@ import pathlib
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal
 
 from . import Finding, suppressed_codes
 
@@ -180,12 +181,12 @@ def _check_rng(jaxpr, tainted: set, in_shard_map: bool, label: str,
         if name == "axis_index":
             tainted.update(eqn.outvars)
             continue
-        any_taint = any(not isinstance(v, jax.core.Literal) and v in tainted
+        any_taint = any(not isinstance(v, Literal) and v in tainted
                         for v in eqn.invars)
         for sub, outer in _sub_jaxprs(eqn):
             inner = (set(sub.invars) if any_taint else set()) if outer is None \
                 else {iv for iv, ov in zip(sub.invars, outer)
-                      if not isinstance(ov, jax.core.Literal) and ov in tainted}
+                      if not isinstance(ov, Literal) and ov in tainted}
             _check_rng(sub, inner, in_shard_map or name == "shard_map",
                        label, findings)
         if in_shard_map and name in _RNG_CONSUMERS and not any_taint:
@@ -303,8 +304,6 @@ def sync_trace(method: str, mode: str) -> SyncTrace:
     from repro.dist.train_step import (TrainStepConfig, _make_sync_fn,
                                        init_ef_state, init_telemetry_state,
                                        local_bucket_sizes)
-
-    from repro.dist import compat  # noqa: F401  (installs AxisType/make_mesh shims)
 
     _require_devices()
     AxisType = jax.sharding.AxisType

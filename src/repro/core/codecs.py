@@ -105,7 +105,7 @@ def decode_reduce(cfg: CompressorConfig, words: jax.Array, levels: jax.Array, n:
     is the sequential-peer jnp oracle from ``kernels.ref``, which runs the
     same op sequence (bit-exact for codebook methods, ulp-level FMA slack
     for the uniform dequant — see ``tests/test_decode_kernels.py``) and is
-    safe under shard_map tracing on the pinned toolchain.  Every peer of a
+    safe under shard_map tracing.  Every peer of a
     collective runs one compiled program over identical gathered bytes, so
     peers agree bit-for-bit on the result regardless of path (the
     peer-agreement contract).

@@ -93,8 +93,8 @@ def stochastic_encode(g: jax.Array, meta: QuantMeta, key: jax.Array) -> jax.Arra
     k = jnp.clip(jnp.searchsorted(levels, gt, side="right") - 1, 0, s - 1)
     lo = levels[k]
     hi = levels[k + 1]
-    pr = (gt - lo) / jnp.maximum(hi - lo, _EPS)
-    up = jax.random.uniform(key, g.shape) < pr
+    # u < (gt − lo)/(hi − lo), division-free as in the kernels
+    up = jax.random.uniform(key, g.shape) * jnp.maximum(hi - lo, _EPS) < gt - lo
     return (k + up.astype(k.dtype)).astype(jnp.uint8)
 
 

@@ -2,7 +2,7 @@
 
 Modules:
 
-- :mod:`repro.dist.compat`        — JAX version shims + manual-region collective helpers
+- :mod:`repro.dist.compat`        — manual-region collective helpers
 - :mod:`repro.dist.sharding`      — logical-axis annotations and logical→mesh rules
 - :mod:`repro.dist.sharded_codec` — quantized reduce-scatter / ring-mean wire codecs
 - :mod:`repro.dist.train_step`    — jitted shard_map train step (dsgd / two_phase /
@@ -14,8 +14,7 @@ Modules:
 :func:`repro.dist.sharding.shard`), so they are exposed lazily to keep the
 ``models ⇄ dist`` import cycle one-directional at package-init time.
 """
-from . import compat  # noqa: F401  (must import first: installs jax shims)
-from . import collectives, sharded_codec, sharding
+from . import collectives, compat, sharded_codec, sharding
 from .collectives import wire_bytes_per_device
 from .sharding import shard
 

@@ -1,78 +1,20 @@
-"""JAX version-compatibility layer for the distributed runtime.
+"""Collective helpers for the fully-manual shard_map regions of the sync.
 
-The runtime (and its tests) target the modern JAX surface — ``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``, ``jax.sharding.AxisType`` — while the
-pinned toolchain ships jax 0.4.37, where ``shard_map`` still lives in
-``jax.experimental`` (with ``check_rep``/``auto`` instead of
-``check_vma``/``axis_names``) and meshes carry no axis types.  Importing this
-module installs forward-compatible shims onto ``jax`` when the modern names
-are missing; on newer JAX every shim is a no-op.
-
-It also provides thin collective helpers used by the sharded codec.  These
-assume *fully manual* shard_map regions: the bundled jaxlib's SPMD
-partitioner aborts on ``all_gather`` / ``all_to_all`` / ``axis_index`` (and
-on any ``lax.scan``) inside manual subgroups when auto axes are present,
-which is why the train step never runs model code under partial-auto —
-fwd/bwd is plain GSPMD jit and only the gradient codec enters shard_map,
-fully manual over every mesh axis.
+The gradient codec runs inside a ``jax.shard_map`` that is manual over every
+mesh axis, while the model's forward/backward stays in the GSPMD-auto jit
+around it.  That split dates from an older jaxlib whose SPMD partitioner
+aborted on ``all_gather`` / ``all_to_all`` / ``axis_index`` (and on any
+``lax.scan``) inside manual subgroups when auto axes were present.  Whether
+jax 0.9.0 lowers a partial-auto sync (model axes auto, data axes manual)
+around the scanned model is untested; until it is, every sync stays fully
+manual and these helpers assume it.
 """
 from __future__ import annotations
 
-import enum
-import inspect
 from collections.abc import Sequence
 
 import jax
 import jax.numpy as jnp
-
-
-def _install_axis_type() -> None:
-    if hasattr(jax.sharding, "AxisType"):
-        return
-
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    jax.sharding.AxisType = AxisType
-
-
-def _install_make_mesh() -> None:
-    sig = inspect.signature(jax.make_mesh)
-    if "axis_types" in sig.parameters:
-        return
-    orig = jax.make_mesh
-
-    def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-        del axis_types  # pre-AxisType meshes are implicitly fully Auto
-        return orig(axis_shapes, axis_names, devices=devices)
-
-    jax.make_mesh = make_mesh
-
-
-def _install_shard_map() -> None:
-    if hasattr(jax, "shard_map"):
-        return
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None, *, axis_names=None,
-                  check_vma=None, check_rep=None, auto=frozenset()):
-        if axis_names is not None and mesh is not None:
-            auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if check_rep is None:
-            check_rep = bool(check_vma) if check_vma is not None else False
-        return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=check_rep, auto=auto)
-
-    jax.shard_map = shard_map
-
-
-_install_axis_type()
-_install_make_mesh()
-_install_shard_map()
-
-shard_map = jax.shard_map
 
 
 def axis_size(axis_name) -> int:

@@ -57,7 +57,7 @@ the reference replay and the per-leaf codec: ``use_pallas`` selects the
 codes and the dequantized ``own`` tensor never reach HBM), otherwise the
 key-compatible sequential oracles in ``kernels.ref`` run the same op
 sequence (bit-identical wire words; uniform dequant keeps ulp-level FMA
-slack) and stay shard_map-safe on the pinned toolchain.
+slack) and stay shard_map-safe.
 
 Peer RNG independence: every encode folds ``compat.flat_axis_index`` of the
 collective's own axes into the key.  The paper's Lemma 2 (mean error

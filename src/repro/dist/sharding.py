@@ -29,7 +29,6 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import compat  # noqa: F401  (installs the jax.shard_map / AxisType shims)
 
 # Logical axes that live on the tensor-parallel mesh axis.
 MODEL_AXES = ("vocab", "heads", "kv_heads", "ff", "expert")

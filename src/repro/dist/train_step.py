@@ -10,8 +10,8 @@
    worker's gradient — the DSGD worker model, with tensor/expert parallelism
    and fsdp parameter sharding left to the partitioner.
 2. **Manual (shard_map) region** — the stacked gradients enter a fully
-   manual shard_map (every mesh axis manual; the pinned toolchain cannot mix
-   manual data axes with auto model axes around ``lax.scan``) where the
+   manual shard_map (every mesh axis manual; see ``dist.compat`` for why
+   the sync does not mix manual data axes with auto model axes) where the
    selected mode averages clients with real collectives:
 
    ======================  ====================================================
@@ -35,6 +35,7 @@ is live at a time.  It is numerically equivalent to the plain schedule.
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any
 
 import jax
@@ -493,7 +494,7 @@ def _make_sync_fn(ts: TrainStepConfig, mesh, pspecs: Any, grads_like: Any):
     if ts.metrics_compression:
         out_specs.append(obs_metrics.CompressionMetrics(
             *(P(dp) for _ in obs_metrics.CompressionMetrics._fields)))
-    return compat.shard_map(
+    return jax.shard_map(
         sync, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=tuple(out_specs) if len(out_specs) > 1 else out_specs[0],
         axis_names=set(mesh.axis_names), check_vma=False,
@@ -588,6 +589,10 @@ def make_train_step(
     buckets inside the sync region and omitted under
     ``ts.metrics_gnorm=False``).  ``pspecs`` is the parameter PartitionSpec
     tree the caller uses for ``device_put``.
+
+    The state arguments (params, optimizer state and, when present, the EF
+    and telemetry states) are donated: callers rebind them to the step's
+    outputs and never read the passed-in arrays again.
 
     Metrics contract (pinned by ``tests/test_obs.py``): ``metrics["loss"]``
     is ALWAYS shape ``(max(n_dp, 1),)`` float32, under every sync mode;
@@ -726,22 +731,25 @@ def make_train_step(
             metrics["live_count"] = jnp.full((max(n_dp, 1),), jnp.sum(live), jnp.float32)
         return new_params, new_opt, new_ef, new_t, metrics
 
+    # The state arguments (params, optimizer state, EF, telemetry) are
+    # donated: each step's outputs reuse their buffers, so the step holds
+    # one copy of the training state rather than two.
     if ts.error_feedback and adaptive:
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(0, 1, 2, 3))
         def step_fn(params, opt_state, ef_state, tstate, batch_g, step):
             return _step(params, opt_state, ef_state, tstate, batch_g, step)
     elif ts.error_feedback:
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(0, 1, 2))
         def step_fn(params, opt_state, ef_state, batch_g, step):
             p, o, e, _, m = _step(params, opt_state, ef_state, None, batch_g, step)
             return p, o, e, m
     elif adaptive:
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(0, 1, 2))
         def step_fn(params, opt_state, tstate, batch_g, step):
             p, o, _, t, m = _step(params, opt_state, None, tstate, batch_g, step)
             return p, o, t, m
     else:
-        @jax.jit
+        @partial(jax.jit, donate_argnums=(0, 1))
         def step_fn(params, opt_state, batch_g, step):
             p, o, _, _, m = _step(params, opt_state, None, None, batch_g, step)
             return p, o, m

@@ -22,7 +22,7 @@ tile for one row block stays resident while every peer's contribution is
 folded in (zero-init at peer 0, divide by n at the last peer — the mean is a
 *sequential* peer accumulation, which the ``ref`` oracles and the
 shard_map-safe jnp fallbacks reproduce op-for-op: bit-exact for the
-codebook variants, whose dequant is an exact one-hot lookup; ulp-level FMA
+codebook variants, whose dequant is an exact table lookup; ulp-level FMA
 discretion remains for the uniform multiply-add dequant).
 
 Word layout matches ``core.quantizers.pack_codes``: flat element i lives in
@@ -31,9 +31,9 @@ occupy word columns ``[g·bits, (g+1)·bits)``.  Reshaped to the kernel's
 (rows, 128) element tiling that is exactly (rows, 4·bits) words per row.
 
 Tiling: BLOCK_ROWS=128 for the uniform kernels (working set ≈ 0.5 MB);
-BLOCK_ROWS=64 for the codebook kernels, whose one-hot (block_elems, s+1)
-dequant matmul on the MXU peaks at 8 MB for b=8 (s+1=256) and well under
-1 MB at the paper-default b=3.
+BLOCK_ROWS=64 for the codebook kernels.  The per-peer codebooks (or alphas)
+ride whole and flat in SMEM, read as scalars at the grid's peer index; the
+codebook dequant is a select chain over them (``quantize.codebook_lookup``).
 """
 from __future__ import annotations
 
@@ -43,9 +43,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .quantize import codebook_lookup, smem_spec
+
 LANES = 128
 BLOCK_ROWS = 128            # uniform decode tiles
-BLOCK_ROWS_CODEBOOK = 64    # bounds the one-hot dequant matmul at s+1=256
+BLOCK_ROWS_CODEBOOK = 64    # codebook decode tiles
 
 
 def words_per_row(bits: int) -> int:
@@ -74,21 +76,16 @@ def _unpack_block(words: jax.Array, bits: int) -> jax.Array:
 
 def _uniform_vals(words_ref, alpha_ref, *, s: int, bits: int) -> jax.Array:
     codes = _unpack_block(words_ref[0], bits).astype(jnp.float32)
-    alpha = alpha_ref[0, 0]
+    alpha = alpha_ref[pl.program_id(1)]
     step = 2.0 * alpha / s
     return codes * step - alpha
 
 
 def _codebook_vals(words_ref, levels_ref, *, s: int, bits: int) -> jax.Array:
-    levels = levels_ref[0]                                        # (s+1,)
+    # Dequant as a select chain over this peer's s+1 scalar levels (SMEM):
+    # an exact table lookup with no gather and no MXU rounding.
     codes = _unpack_block(words_ref[0], bits)
-    bm = codes.shape[0]
-    flat = codes.reshape(bm * LANES).astype(jnp.float32)
-    # Dequant as a one-hot matmul on the MXU (no gathers on TPU); each row of
-    # the one-hot has exactly one 1, so the product is an exact table lookup.
-    iota = jax.lax.broadcasted_iota(jnp.float32, (bm * LANES, s + 1), 1)
-    onehot = (iota == flat[:, None]).astype(jnp.float32)
-    return (onehot @ levels).reshape(bm, LANES)
+    return codebook_lookup(levels_ref, codes, s=s, base=pl.program_id(1) * (s + 1))
 
 
 def _reduce_tail(out_ref, vals: jax.Array, n_peers: int) -> None:
@@ -128,9 +125,11 @@ def _call(kernel, words3: jax.Array, meta2: jax.Array, *, bits: int, block_rows:
 
     ``words3``: (n_peers, rows_p, 4·bits) int32 with rows_p a multiple of
     ``block_rows``; ``meta2``: (n_peers, k) fp32 per-peer codebook operand
-    ((n, 1) alphas or (n, s+1) levels).  ``reduce=True`` accumulates the peer
-    mean into one (rows_p, 128) tile set; ``reduce=False`` writes each peer's
-    decode into its own (rows_p, 128) band of a (n_peers, rows_p, 128) output.
+    ((n, 1) alphas or (n, s+1) levels), passed flat and whole in SMEM (a
+    per-peer (1, k) VMEM block would break the (8, 128) tiling).
+    ``reduce=True`` accumulates the peer mean into one (rows_p, 128) tile
+    set; ``reduce=False`` writes each peer's decode into its own
+    (rows_p, 128) band of a (n_peers, rows_p, 128) output.
     """
     n_peers, rows_p, wc = words3.shape
     assert wc == words_per_row(bits) and rows_p % block_rows == 0
@@ -148,12 +147,12 @@ def _call(kernel, words3: jax.Array, meta2: jax.Array, *, bits: int, block_rows:
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_rows, wc), lambda i, p: (p, i, 0)),
-            pl.BlockSpec((1, meta2.shape[1]), lambda i, p: (p, 0)),
+            smem_spec(),
         ],
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(words3, meta2)
+    )(words3, meta2.reshape(-1))
 
 
 def uniform_decode_reduce_3d(words3, alphas2, *, bits: int, interpret: bool) -> jax.Array:

@@ -36,7 +36,7 @@ kernels collapse that pipeline to two VMEM passes:
 
 Tiling matches ``kernels.quantize``: (rows, 128) fp32 blocked
 (BLOCK_ROWS, 128) per grid step; the stats kernel uses the smaller
-``stats.BLOCK_ROWS`` tile that bounds its one-hot histogram matmul.
+``stats.BLOCK_ROWS`` tile of the standalone stats kernel.
 """
 from __future__ import annotations
 
@@ -53,6 +53,8 @@ from .quantize import (
     _mask_tail,
     _pack_block,
     codebook_select,
+    smem_spec,
+    uniform_scalars,
     uniform_select,
 )
 
@@ -98,7 +100,7 @@ def ef_correct_stats_2d(
         _ef_correct_stats_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=None),       # n: full (1,) operand
+            smem_spec(),  # n: full (1,) operand
             pl.BlockSpec((_s.BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((_s.BLOCK_ROWS, LANES), lambda i: (i, 0)),
         ],
@@ -121,7 +123,7 @@ def ef_correct_stats_2d(
 
 def _uniform_encode_pack_kernel(n_ref, alpha_ref, g_ref, rand_ref, words_ref, *, s, bits):
     g = g_ref[...]
-    code_f = uniform_select(alpha_ref[0], g, rand_ref[...], s=s)
+    code_f = uniform_select(alpha_ref[0], alpha_ref[1], g, rand_ref[...], s=s)
     codes = _mask_tail(code_f.astype(jnp.int32), n_ref, g.shape[0])
     words_ref[...] = _pack_block(codes, bits)
 
@@ -130,7 +132,7 @@ def _uniform_encode_pack_resid_kernel(n_ref, alpha_ref, g_ref, rand_ref, words_r
                                       resid_ref, *, s, bits):
     alpha = alpha_ref[0]
     g = g_ref[...]
-    code_f = uniform_select(alpha, g, rand_ref[...], s=s)
+    code_f = uniform_select(alpha, alpha_ref[1], g, rand_ref[...], s=s)
     codes = _mask_tail(code_f.astype(jnp.int32), n_ref, g.shape[0])
     words_ref[...] = _pack_block(codes, bits)
     step = 2.0 * alpha / s
@@ -139,18 +141,18 @@ def _uniform_encode_pack_resid_kernel(n_ref, alpha_ref, g_ref, rand_ref, words_r
 
 def _codebook_encode_pack_kernel(n_ref, g_ref, rand_ref, levels_ref, words_ref, *, s, bits):
     g = g_ref[...]
-    code_f, _ = codebook_select(levels_ref[...], g, rand_ref[...], s=s)
-    codes = _mask_tail(code_f.reshape(g.shape).astype(jnp.int32), n_ref, g.shape[0])
+    code_f, _ = codebook_select(levels_ref, g, rand_ref[...], s=s)
+    codes = _mask_tail(code_f.astype(jnp.int32), n_ref, g.shape[0])
     words_ref[...] = _pack_block(codes, bits)
 
 
 def _codebook_encode_pack_resid_kernel(n_ref, g_ref, rand_ref, levels_ref, words_ref,
                                        resid_ref, *, s, bits):
     g = g_ref[...]
-    code_f, val = codebook_select(levels_ref[...], g, rand_ref[...], s=s)
-    codes = _mask_tail(code_f.reshape(g.shape).astype(jnp.int32), n_ref, g.shape[0])
+    code_f, val = codebook_select(levels_ref, g, rand_ref[...], s=s)
+    codes = _mask_tail(code_f.astype(jnp.int32), n_ref, g.shape[0])
     words_ref[...] = _pack_block(codes, bits)
-    resid_ref[...] = g - val.reshape(g.shape)
+    resid_ref[...] = g - val
 
 
 def _call_encode(kernel, operands, rows: int, *, bits: int, residual: bool,
@@ -166,7 +168,7 @@ def _call_encode(kernel, operands, rows: int, *, bits: int, residual: bool,
     grid = (pl.cdiv(rows, BLOCK_ROWS),)
     in_specs = [
         pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)) if blocked
-        else pl.BlockSpec(memory_space=None)
+        else smem_spec()
         for _, blocked in operands
     ]
     out_specs = [pl.BlockSpec((BLOCK_ROWS, wc), lambda i: (i, 0))]
@@ -187,7 +189,7 @@ def _call_encode(kernel, operands, rows: int, *, bits: int, residual: bool,
 def uniform_encode_pack_2d(g, rand, alpha, n: int, *, bits: int, interpret: bool):
     """Fused uniform encode + bit-pack, words only: (rows, 4·bits) int32."""
     s = 2**bits - 1
-    ops = [(jnp.asarray([n], jnp.int32), False), (alpha.reshape(1), False),
+    ops = [(jnp.asarray([n], jnp.int32), False), (uniform_scalars(alpha, s), False),
            (g, True), (rand, True)]
     return _call_encode(_uniform_encode_pack_kernel, ops, g.shape[0],
                         bits=bits, residual=False, interpret=interpret, s=s)
@@ -197,7 +199,7 @@ def uniform_encode_pack_resid_2d(g, rand, alpha, n: int, *, bits: int, interpret
     """Fused uniform encode + bit-pack + residual ``g − dequant(code)``.
     Returns ((rows, 4·bits) int32 words, (rows, 128) fp32 residual)."""
     s = 2**bits - 1
-    ops = [(jnp.asarray([n], jnp.int32), False), (alpha.reshape(1), False),
+    ops = [(jnp.asarray([n], jnp.int32), False), (uniform_scalars(alpha, s), False),
            (g, True), (rand, True)]
     return _call_encode(_uniform_encode_pack_resid_kernel, ops, g.shape[0],
                         bits=bits, residual=True, interpret=interpret, s=s)

@@ -6,10 +6,12 @@ truncate → scale → stochastic-round → code  into one VMEM pass.
 
 Tiling: inputs are reshaped to (rows, 128) — the 128-lane register width —
 and blocked (BLOCK_ROWS, 128) per grid step.  BLOCK_ROWS=256 keeps the
-working set (g + rand + codes + codebook compare matrix) well under VMEM:
-uniform:  256·128·(4+4+4) B ≈ 0.4 MB;
-codebook: adds a (s+1,) broadcast and two one-hot (256·128, s+1) matmuls on
-the MXU at s+1 ≤ 256 ⇒ ≈ 16 MB peak for b=8; b=3 (paper default) ≈ 1 MB.
+working set (g + rand + codes) at 256·128·(4+4+4) B ≈ 0.4 MB; the codebook
+variants add a few same-shape temporaries (interval index, lo, hi).  The
+(s+1,) codebook and the scalars ride whole in SMEM: each level is read as a
+scalar and broadcast, so the codebook walk is s compares plus s+1 selects
+per element, with no gather (Mosaic lowers only 2-D gathers) and no MXU
+pass (whose fp32 rounding would make the lookup inexact).
 
 Codes are emitted as int32 in-kernel (TPU stores are word-aligned; the
 wrapper narrows to uint8 / packs to uint32 lanes outside).
@@ -21,55 +23,83 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 BLOCK_ROWS = 256
+MAX_UNROLL = 16    # codebook loops unroll fully up to 4 bits (Mosaic: all or none)
 
 
-def uniform_select(alpha, g: jax.Array, rand: jax.Array, *, s: int) -> jax.Array:
+def uniform_scalars(alpha: jax.Array, s: int) -> jax.Array:
+    """(2,) fp32 ``[α, s/(2α)]`` SMEM operand of the uniform encode kernels.
+
+    The scale is divided out here, by the same XLA op the ``kernels.ref``
+    oracle runs, rather than in the kernel: a scale that differed in its
+    last bit would move every element's rounding point and break the
+    bit-identical wire contract between kernel and oracle on the chip.
+    """
+    alpha = alpha.astype(jnp.float32).reshape(())
+    return jnp.stack([alpha, s / (2.0 * alpha)])
+
+
+def uniform_select(alpha, scale, g: jax.Array, rand: jax.Array, *, s: int) -> jax.Array:
     """Shared uniform stochastic-encode body -> float codes in [0, s].
 
     The single source of the truncate → scale → floor → stochastic-round
     math for every uniform encode kernel (the plain, the packed, and the
     fused residual variants in ``encode_fused``) — keeping the bit-identical
-    wire contract between kernel families in one place.
+    wire contract between kernel families in one place.  ``scale`` is
+    ``s/(2α)`` from :func:`uniform_scalars`.
     """
-    scale = s / (2.0 * alpha)
     u = (jnp.clip(g, -alpha, alpha) + alpha) * scale
     k = jnp.clip(jnp.floor(u), 0.0, float(s - 1))
     up = (rand < (u - k)).astype(jnp.float32)
     return jnp.clip(k + up, 0.0, float(s))
 
 
-def codebook_select(levels: jax.Array, g: jax.Array, rand: jax.Array,
+def codebook_select(levels_ref, g: jax.Array, rand: jax.Array,
                     *, s: int) -> tuple[jax.Array, jax.Array]:
     """Shared codebook stochastic-encode body.
 
-    Returns ``(codes float (BM·128,), dequant value (BM·128,))``: the
-    dequant is the interval endpoint the rounding chose (``hi`` on
-    round-up, ``lo`` otherwise), which equals ``levels[code]`` exactly —
-    callers that don't need it (the non-residual kernels) leave it dead for
-    DCE.  Single source of the compare-count + one-hot lo/hi + pr math for
-    every codebook encode kernel.
+    ``levels_ref`` holds the (s+1,) codebook in scalar memory (SMEM); each
+    level is read as a scalar and broadcast, so the kernel needs no gather
+    (Mosaic lowers only 2-D gathers) and no MXU pass (whose fp32 precision
+    would make the lookup inexact).  Returns ``(codes float, dequant value)``
+    in ``g``'s (BM, 128) layout: the dequant is the interval endpoint the
+    rounding chose (``hi`` on round-up, ``lo`` otherwise), which equals
+    ``levels[code]`` exactly — callers that don't need it (the non-residual
+    kernels) leave it dead for DCE.  Single source of the compare-count,
+    lo/hi select and rounding decision for every codebook encode kernel.
     """
-    alpha = levels[s]
-    bm = g.shape[0]
+    alpha = levels_ref[s]
     gt = jnp.clip(g, -alpha, alpha)
-    flat = gt.reshape(bm * LANES)
-    ge = (flat[:, None] >= levels[None, 1:]).astype(jnp.float32)
-    k = jnp.clip(jnp.sum(ge, axis=1), 0.0, float(s - 1))
-    iota = jax.lax.broadcasted_iota(jnp.float32, (flat.shape[0], s + 1), 1)
-    onehot_lo = (iota == k[:, None]).astype(jnp.float32)
-    onehot_hi = (iota == (k[:, None] + 1.0)).astype(jnp.float32)
-    lo = onehot_lo @ levels
-    hi = onehot_hi @ levels
-    pr = (flat - lo) / jnp.maximum(hi - lo, 1e-12)
-    up = (rand.reshape(bm * LANES) < pr).astype(jnp.float32)
+
+    def count(j, k):
+        return k + (gt >= levels_ref[j]).astype(jnp.float32)
+
+    k = jax.lax.fori_loop(1, s + 1, count, jnp.zeros_like(gt), unroll=s < MAX_UNROLL)
+    k = jnp.clip(k, 0.0, float(s - 1))
+
+    def pick(j, lo_hi):
+        lv, jf = levels_ref[j], j.astype(jnp.float32)
+        return (jnp.where(k == jf, lv, lo_hi[0]),
+                jnp.where(k == jf - 1.0, lv, lo_hi[1]))
+
+    lo, hi = jax.lax.fori_loop(0, s + 1, pick, (jnp.zeros_like(gt), jnp.zeros_like(gt)),
+                               unroll=s < MAX_UNROLL)
+    # rand < (gt − lo)/(hi − lo), without the division: XLA and Mosaic
+    # round an fp32 quotient differently, a product they round alike
+    up = (rand * jnp.maximum(hi - lo, 1e-12) < gt - lo).astype(jnp.float32)
     return k + up, jnp.where(up > 0.0, hi, lo)
 
 
+def smem_spec() -> pl.BlockSpec:
+    """Whole small operand (scalars, codebooks) in scalar memory."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
 def _uniform_encode_kernel(alpha_ref, g_ref, rand_ref, out_ref, *, s: int):
-    out_ref[...] = uniform_select(alpha_ref[0], g_ref[...], rand_ref[...],
+    out_ref[...] = uniform_select(alpha_ref[0], alpha_ref[1], g_ref[...], rand_ref[...],
                                   s=s).astype(jnp.int32)
 
 
@@ -84,14 +114,14 @@ def uniform_encode_2d(
         functools.partial(_uniform_encode_kernel, s=s),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=None),       # alpha: full (1,) operand
+            smem_spec(),  # [alpha, scale]
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         interpret=interpret,
-    )(alpha.reshape(1), g, rand)
+    )(uniform_scalars(alpha, s), g, rand)
 
 
 def _uniform_decode_kernel(alpha_ref, codes_ref, out_ref, *, s: int):
@@ -110,7 +140,7 @@ def uniform_decode_2d(
         functools.partial(_uniform_decode_kernel, s=s),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=None),
+            smem_spec(),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
@@ -120,11 +150,10 @@ def uniform_decode_2d(
 
 
 def _codebook_encode_kernel(g_ref, rand_ref, levels_ref, out_ref, *, s: int):
-    # compare-count interval index + one-hot lo/hi matmuls on the MXU (no
-    # gathers on TPU) — shared body in codebook_select
-    g = g_ref[...]
-    code_f, _ = codebook_select(levels_ref[...], g, rand_ref[...], s=s)
-    out_ref[...] = code_f.reshape(g.shape).astype(jnp.int32)
+    # compare-count interval index + select lo/hi — shared body in
+    # codebook_select
+    code_f, _ = codebook_select(levels_ref, g_ref[...], rand_ref[...], s=s)
+    out_ref[...] = code_f.astype(jnp.int32)
 
 
 def codebook_encode_2d(
@@ -139,7 +168,7 @@ def codebook_encode_2d(
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=None),       # levels: full operand
+            smem_spec(),  # levels: full operand
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
@@ -184,7 +213,7 @@ def _mask_tail(codes: jax.Array, n_ref, bm: int) -> jax.Array:
 def _uniform_encode_pack_kernel(n_ref, alpha_ref, g_ref, rand_ref, codes_ref, words_ref,
                                 *, s: int, bits: int):
     g = g_ref[...]
-    code_f = uniform_select(alpha_ref[0], g, rand_ref[...], s=s)
+    code_f = uniform_select(alpha_ref[0], alpha_ref[1], g, rand_ref[...], s=s)
     codes = _mask_tail(code_f.astype(jnp.int32), n_ref, g.shape[0])
     codes_ref[...] = codes
     words_ref[...] = _pack_block(codes, bits)
@@ -203,8 +232,8 @@ def uniform_encode_pack_2d(
         functools.partial(_uniform_encode_pack_kernel, s=s, bits=bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=None),       # n: full (1,) operand
-            pl.BlockSpec(memory_space=None),       # alpha: full (1,) operand
+            smem_spec(),  # n: full (1,) operand
+            smem_spec(),  # [alpha, scale]
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         ],
@@ -217,14 +246,14 @@ def uniform_encode_pack_2d(
             jax.ShapeDtypeStruct((rows, wc), jnp.int32),
         ],
         interpret=interpret,
-    )(jnp.asarray([n], jnp.int32), alpha.reshape(1), g, rand)
+    )(jnp.asarray([n], jnp.int32), uniform_scalars(alpha, s), g, rand)
 
 
 def _codebook_encode_pack_kernel(n_ref, g_ref, rand_ref, levels_ref, codes_ref, words_ref,
                                  *, s: int, bits: int):
     g = g_ref[...]
-    code_f, _ = codebook_select(levels_ref[...], g, rand_ref[...], s=s)
-    codes = _mask_tail(code_f.reshape(g.shape).astype(jnp.int32), n_ref, g.shape[0])
+    code_f, _ = codebook_select(levels_ref, g, rand_ref[...], s=s)
+    codes = _mask_tail(code_f.astype(jnp.int32), n_ref, g.shape[0])
     codes_ref[...] = codes
     words_ref[...] = _pack_block(codes, bits)
 
@@ -241,10 +270,10 @@ def codebook_encode_pack_2d(
         functools.partial(_codebook_encode_pack_kernel, s=s, bits=bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec(memory_space=None),       # n: full (1,) operand
+            smem_spec(),  # n: full (1,) operand
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=None),       # levels: full operand
+            smem_spec(),  # levels: full operand
         ],
         out_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
@@ -259,13 +288,17 @@ def codebook_encode_pack_2d(
 
 
 def _codebook_decode_kernel(codes_ref, levels_ref, out_ref, *, s: int):
-    levels = levels_ref[...]
-    codes = codes_ref[...].astype(jnp.float32)
-    bm = codes.shape[0]
-    flat = codes.reshape(bm * LANES)
-    iota = jax.lax.broadcasted_iota(jnp.float32, (flat.shape[0], s + 1), 1)
-    onehot = (iota == flat[:, None]).astype(jnp.float32)
-    out_ref[...] = (onehot @ levels).reshape(bm, LANES)
+    out_ref[...] = codebook_lookup(levels_ref, codes_ref[...], s=s)
+
+
+def codebook_lookup(level_ref, codes: jax.Array, *, s: int, base=0) -> jax.Array:
+    """Exact ``levels[codes]`` as a select chain over the s+1 scalar levels
+    ``level_ref[base + j]`` (SMEM): no gather, no MXU rounding."""
+    def pick(j, vals):
+        return jnp.where(codes == j, level_ref[base + j], vals)
+
+    return jax.lax.fori_loop(0, s + 1, pick, jnp.zeros(codes.shape, jnp.float32),
+                             unroll=s < MAX_UNROLL)
 
 
 def codebook_decode_2d(codes: jax.Array, levels: jax.Array, *, interpret: bool) -> jax.Array:
@@ -277,7 +310,7 @@ def codebook_decode_2d(codes: jax.Array, levels: jax.Array, *, interpret: bool) 
         grid=grid,
         in_specs=[
             pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=None),
+            smem_spec(),
         ],
         out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.float32),

@@ -6,7 +6,6 @@ reference paths can be compared bit-exactly).
 """
 from __future__ import annotations
 
-import math
 
 import jax
 import jax.numpy as jnp
@@ -37,9 +36,9 @@ def uniform_decode(codes: jax.Array, alpha: jax.Array, bits: int) -> jax.Array:
 def codebook_encode(g: jax.Array, levels: jax.Array, rand: jax.Array) -> jax.Array:
     """Fused truncate + non-uniform stochastic quantize onto ``levels``.
 
-    k = #{j in 1..s : g >= l_j} clipped to s-1;  pr = (g - l_k)/(l_{k+1}-l_k);
-    code = k + 1[rand < pr].  Matches quantizers.stochastic_encode given the
-    same uniforms.
+    k = #{j in 1..s : g >= l_j} clipped to s-1;
+    code = k + 1[rand · (l_{k+1} − l_k) < g − l_k]  (rand < pr without the
+    division).  Matches quantizers.stochastic_encode given the same uniforms.
     """
     s = levels.shape[0] - 1
     alpha = levels[-1]
@@ -48,8 +47,8 @@ def codebook_encode(g: jax.Array, levels: jax.Array, rand: jax.Array) -> jax.Arr
     k = jnp.clip(k, 0, s - 1)
     lo = levels[k]
     hi = levels[k + 1]
-    pr = (gt - lo) / jnp.maximum(hi - lo, 1e-12)
-    return (k + (rand < pr).astype(k.dtype)).astype(jnp.uint8)
+    up = rand * jnp.maximum(hi - lo, 1e-12) < gt - lo
+    return (k + up.astype(k.dtype)).astype(jnp.uint8)
 
 
 def codebook_decode(codes: jax.Array, levels: jax.Array) -> jax.Array:
@@ -90,11 +89,11 @@ def uniform_encode_pack(g: jax.Array, alpha: jax.Array, bits: int, key: jax.Arra
 def _codebook_codes(flat: jax.Array, levels: jax.Array, rand: jax.Array) -> jax.Array:
     """Codebook stochastic codes via searchsorted + take.
 
-    Bit-identical to the kernel's compare-count + one-hot formulation
+    Bit-identical to the kernel's compare-count + select formulation
     (:func:`codebook_encode`): the interval index is the same exact integer
-    either way, and ``take`` is the same exact lookup as the one-hot
-    matmul — but a binary search beats the (n, s) compare matrix on CPU,
-    which is what this fallback actually runs on.
+    either way, and ``take`` is the same exact lookup as the select chain —
+    but a binary search beats s compares per element on CPU, which is what
+    this fallback actually runs on.
     """
     levels = levels.astype(jnp.float32)
     s = levels.shape[0] - 1
@@ -102,8 +101,8 @@ def _codebook_codes(flat: jax.Array, levels: jax.Array, rand: jax.Array) -> jax.
     k = jnp.clip(jnp.searchsorted(levels, gt, side="right") - 1, 0, s - 1)
     lo = jnp.take(levels, k)
     hi = jnp.take(levels, k + 1)
-    pr = (gt - lo) / jnp.maximum(hi - lo, 1e-12)
-    return (k + (rand.reshape(-1)[: flat.size] < pr).astype(k.dtype)).astype(jnp.uint8)
+    up = rand.reshape(-1)[: flat.size] * jnp.maximum(hi - lo, 1e-12) < gt - lo
+    return (k + up.astype(k.dtype)).astype(jnp.uint8)
 
 
 def codebook_encode_pack(g: jax.Array, levels: jax.Array, bits: int, key: jax.Array) -> jax.Array:
@@ -158,9 +157,7 @@ def bucket_stats_scatter(g: jax.Array):
     flat = g.reshape(-1).astype(jnp.float32)
     gabs = jnp.abs(flat)
     lnab = jnp.log(jnp.maximum(gabs, 1e-30))
-    w = (S.LOG2_HI - S.LOG2_LO) / S.NUM_BINS
-    b = jnp.clip(jnp.floor((lnab / jnp.log(2.0) - S.LOG2_LO) / w),
-                 0.0, S.NUM_BINS - 1.0).astype(jnp.int32)
+    b = S.bin_index(gabs)
     # repro: allow REPRO104 (counts sum exact 1.0s — order-free; see above)
     counts = jnp.zeros((S.NUM_BINS,), jnp.float32).at[b].add(1.0)
     # repro: allow REPRO104 (last-bit slack documented in the EMA contract)
@@ -183,7 +180,7 @@ def ef_correct_stats(g: jax.Array, e: jax.Array) -> tuple[jax.Array, jax.Array]:
 # (grid peer axis innermost) and divide by n at the last peer; per element
 # that is exactly ``(((v_0 + v_1) + ...) + v_{n-1}) / n`` regardless of the
 # row blocking, so the oracle is a plain per-peer accumulation loop.  All
-# remaining ops are element-wise (or exact one-hot lookups), hence kernel and
+# remaining ops are element-wise (or exact table lookups), hence kernel and
 # oracle agree bit-for-bit in interpret mode — and these same functions
 # double as the shard_map-safe jnp fallback of ``dist.sharded_codec``, which
 # streams one peer at a time instead of materializing the (n_peers, m)
@@ -244,11 +241,12 @@ def codebook_decode_rows(words: jax.Array, levels: jax.Array, n: int, bits: int)
 def bucket_stats(g: jax.Array) -> jax.Array:
     """Blockwise jnp oracle for ``stats.bucket_stats_2d``.
 
-    Walks the padded (rows, 128) layout in the kernel's BLOCK_ROWS grid
-    order, builds each block's log2-spaced histogram / ln-sum / max / moment
-    partials with the same one-hot-matmul reduction, and folds them with the
-    same add-or-maximum merge — so kernel and oracle agree bit-for-bit
-    (interpret mode executes identical ops in identical order).
+    Scans the padded (rows, 128) layout in the kernel's BLOCK_ROWS grid
+    order, runs the kernel's own block body (``stats._block_stats``) on each
+    block and folds the partials with its add-or-maximum merge
+    (``stats._merge``) — so kernel and oracle agree bit-for-bit (interpret
+    mode executes identical ops in identical order).  A scan, not an
+    unrolled loop: the program stays one block body at any bucket size.
     """
     from . import stats as S
 
@@ -256,37 +254,16 @@ def bucket_stats(g: jax.Array) -> jax.Array:
     n = flat.size
     rows = -(-n // S.LANES)
     blocks = -(-rows // S.BLOCK_ROWS)
-    padded = jnp.pad(flat, (0, blocks * S.BLOCK_ROWS * S.LANES - n))
-    w = (S.LOG2_HI - S.LOG2_LO) / S.NUM_BINS
-    acc = jnp.zeros((S.STATS_ROWS, S.NUM_BINS), jnp.float32)
-    for i in range(blocks):
-        m = S.BLOCK_ROWS * S.LANES
-        gb = padded[i * m:(i + 1) * m].reshape(S.BLOCK_ROWS, S.LANES)
-        valid = (jnp.arange(i * m, (i + 1) * m) < n).reshape(S.BLOCK_ROWS, S.LANES)
-        vmask = valid.astype(jnp.float32)
-        gabs = jnp.abs(gb) * vmask
-        lnab = jnp.log(jnp.maximum(gabs, 1e-30))
-        b = jnp.clip(jnp.floor((lnab / math.log(2.0) - S.LOG2_LO) / w),
-                     0.0, S.NUM_BINS - 1.0)
-        b = jnp.where(valid, b, -1.0)
-        iota = jax.lax.broadcasted_iota(jnp.float32, (m, S.NUM_BINS), 1)
-        onehot = (iota == b.reshape(m)[:, None]).astype(jnp.float32)
-        counts = jnp.ones((1, m), jnp.float32) @ onehot
-        logsum = (lnab * vmask).reshape(1, m) @ onehot
-        gv = gb * vmask
-        part = jnp.concatenate(
-            [
-                counts,
-                logsum,
-                jnp.full((1, S.NUM_BINS), jnp.max(gabs), jnp.float32),
-                jnp.full((1, S.NUM_BINS), jnp.sum(gv), jnp.float32),
-                jnp.full((1, S.NUM_BINS), jnp.sum(gv * gv), jnp.float32),
-                jnp.zeros((S.STATS_ROWS - 5, S.NUM_BINS), jnp.float32),
-            ],
-            axis=0,
-        )
-        row = jax.lax.broadcasted_iota(jnp.int32, (S.STATS_ROWS, S.NUM_BINS), 0)
-        acc = jnp.where(row == 2, jnp.maximum(acc, part), acc + part)
+    m = S.BLOCK_ROWS * S.LANES
+    padded = jnp.pad(flat, (0, blocks * m - n)).reshape(blocks, S.BLOCK_ROWS, S.LANES)
+    idx = jnp.arange(m, dtype=jnp.int32).reshape(S.BLOCK_ROWS, S.LANES)
+
+    def fold(acc, xs):
+        i, gb = xs
+        return S._merge(acc, S._block_stats(gb, idx + i * m < n)), None
+
+    acc0 = jnp.zeros((S.STATS_ROWS, S.NUM_BINS), jnp.float32)
+    acc, _ = jax.lax.scan(fold, acc0, (jnp.arange(blocks, dtype=jnp.int32), padded))
     return acc
 
 

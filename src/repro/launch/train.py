@@ -1,11 +1,16 @@
 """Training launcher: ``python -m repro.launch.train --arch <id> [...]``.
 
 Runs on whatever devices exist (CPU: mesh 1x1 by default).  Use
-``--fake-devices N`` to exercise the distributed path on a host mesh.
+``--fake-devices N`` to exercise the distributed path on a host mesh.  On a
+TPU backend the gradient codec runs the Pallas kernels (``use_pallas``);
+elsewhere it runs their jnp oracles.  The step is compiled ahead of the
+loop, and the compile time and the number of Pallas kernel calls in the
+compiled step are printed.
 """
 import argparse
 import os
 import sys
+import time
 
 
 def _early_args(argv):
@@ -34,6 +39,7 @@ def main(argv=None) -> int:
     from repro.core.compressors import CompressorConfig
     from repro.data.synthetic import lm_batch
     from repro.dist.train_step import SYNC_MODES, TrainStepConfig, make_train_step
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh_from_spec
     from repro.models import init_lm
     from repro.optim.optimizers import get_optimizer
@@ -85,12 +91,18 @@ def main(argv=None) -> int:
                     help="steps between compression-metric events")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = make_reduced(cfg)
     mesh = make_mesh_from_spec(args.mesh or str(len(jax.devices())))
-    print(f"arch={cfg.name} mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
-          f"sync={args.sync} method={args.method} bits={args.bits}")
+    # the kernels on the chip; their jnp oracles everywhere else
+    use_pallas = jax.default_backend() == "tpu"
+    print(f"arch={cfg.name} layers={cfg.num_layers} d_model={cfg.d_model} "
+          f"d_ff={cfg.d_ff} vocab={cfg.vocab} batch={args.batch} seq={args.seq} "
+          f"mesh={dict(zip(mesh.axis_names, mesh.devices.shape))} "
+          f"sync={args.sync} method={args.method} bits={args.bits} "
+          f"codec={'pallas' if use_pallas else 'jnp'}")
 
     # repro: allow REPRO204 (CLI entry point: the reproducible demo seed)
     params, logical = init_lm(jax.random.key(0), cfg)
@@ -128,7 +140,8 @@ def main(argv=None) -> int:
     ts = TrainStepConfig(sync=args.sync,
                          compressor=CompressorConfig(method=args.method, bits=args.bits,
                                                      rank=args.rank,
-                                                     approx_gmin=args.adaptive),
+                                                     approx_gmin=args.adaptive,
+                                                     use_pallas=use_pallas),
                          bucket_mb=args.bucket_mb, error_feedback=args.ef, adaptive=acfg,
                          elastic=ecfg, fp16_threshold=args.fp16_threshold,
                          metrics_compression=args.obs_dir is not None)
@@ -162,6 +175,13 @@ def main(argv=None) -> int:
                                                        is_leaf=lambda x: isinstance(x, _P)))
     ef_state = init_ef_state(params, mesh, pspecs, ts) if args.ef else None
     tstate = stepper.init_telemetry() if stepper is not None else None
+    if stepper is None:
+        t0 = time.perf_counter()
+        state = (params, opt_state) + ((ef_state,) if args.ef else ())
+        step_fn = step_fn.lower(*state, batch0, jnp.uint32(start)).compile()
+        n_kernels = step_fn.as_text().count('custom_call_target="tpu_custom_call"')
+        print(f"compiled step in {time.perf_counter() - t0:.1f} s "
+              f"({n_kernels} Pallas kernel calls)", flush=True)
 
     import contextlib
 

@@ -10,8 +10,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat  # noqa: F401  (installs shard_map/AxisType shims)
-
 
 def _mesh():
     return jax.make_mesh((1,), ("data",))
@@ -19,7 +17,7 @@ def _mesh():
 
 def _smap(body, in_specs, out_specs):
     return jax.jit(jax.shard_map(
-        body, mesh=_mesh(), in_specs=in_specs, out_specs=out_specs))
+        body, mesh=_mesh(), in_specs=in_specs, out_specs=out_specs, check_vma=False))
 
 
 def correlated_rng():
@@ -60,7 +58,7 @@ def f64_leak():
     def f(x):
         return jnp.sum(x.astype(jnp.float64))
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return jax.jit(f).trace(jnp.zeros((8,), jnp.float32)).jaxpr
 
 

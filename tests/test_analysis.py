@@ -256,7 +256,6 @@ import jax
 from repro.analysis import jaxpr_lint as jl
 from repro.core.codecs import bucket_cfgs, get_codec
 from repro.core.compressors import CompressorConfig
-from repro.dist import compat  # noqa: F401
 from repro.dist.collectives import decode_hbm_bytes, encode_hbm_bytes
 from repro.dist.train_step import TrainStepConfig, local_bucket_sizes
 

@@ -72,3 +72,29 @@ def test_jnp_fallback_agrees_with_kernel():
     np.testing.assert_allclose(np.asarray(got.log_sums), np.asarray(ls), rtol=1e-5, atol=1e-3)
     np.testing.assert_allclose(float(got.g_sum), float(gs), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(float(got.g_sumsq), float(gq), rtol=1e-4)
+
+
+def test_bin_index_is_exact_at_edges():
+    """Bins come from the fp32 bits, not from ``log``: every edge value lands
+    in its own bin and the float just below it in the bin beneath, so the
+    kernel (Mosaic) and the oracles (XLA) cannot disagree near an edge."""
+    edges = S.bin_edges()[1:-1]
+    want = np.arange(1, S.NUM_BINS, dtype=np.float32)
+    np.testing.assert_array_equal(np.asarray(S.bin_index(edges)), want)
+    below = jnp.nextafter(edges, jnp.float32(0.0))
+    np.testing.assert_array_equal(np.asarray(S.bin_index(below)), want - 1)
+    extremes = jnp.asarray([0.0, 1e-30, 256.0, 1e30, jnp.inf], jnp.float32)
+    np.testing.assert_array_equal(np.asarray(S.bin_index(extremes)),
+                                  [0, 0, S.NUM_BINS - 1, S.NUM_BINS - 1, S.NUM_BINS - 1])
+
+
+def test_block_stats_ignore_what_lies_past_the_bucket():
+    """A partial last block reads past the array on the chip, where the
+    buffer may hold NaN or inf: the masked elements must not reach any sum."""
+    g = sample_power_law(jax.random.key(4), (S.BLOCK_ROWS, S.LANES), gamma=3.6,
+                         g_min=0.01, rho=0.15)
+    valid = jnp.arange(g.size).reshape(g.shape) < 1000
+    clean = S._block_stats(jnp.where(valid, g, 0.0), valid)
+    for junk in (jnp.nan, jnp.inf, -jnp.inf):
+        got = S._block_stats(jnp.where(valid, g, junk), valid)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
