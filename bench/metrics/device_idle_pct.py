@@ -1,0 +1,8 @@
+"""Share of the traced window in which no op ran on the device, averaged
+over the cell's chips (the same busy_s and window_s as the result's device)."""
+
+
+def read(ctx):
+    if ctx.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - ctx.busy_s / ctx.window_s)
