@@ -149,7 +149,7 @@ def _eqn_site(eqn):
     try:
         from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         return None
     if frame is None:

@@ -72,6 +72,18 @@ def scatter_add():
     return jax.jit(f).trace(idx, v).jaxpr
 
 
+def suppressed_scatter_add():
+    """The same scatter-add carrying the allow comment: the finding is
+    anchored to this source line, so the comment silences it."""
+    def f(idx, v):
+        # repro: allow REPRO104 (fixture: a documented, order-free scatter)
+        return jnp.zeros((8,), jnp.float32).at[idx].add(v)
+
+    idx = jnp.zeros((16,), jnp.int32)
+    v = jnp.ones((16,), jnp.float32)
+    return jax.jit(f).trace(idx, v).jaxpr
+
+
 def wire_f32():
     """REPRO105: fp32 rows on a compressed-wire collective (the codec
     contract is one uint32 word vector per bucket)."""

@@ -9,10 +9,10 @@ dispatch branches are covered on every PR.  The option is exported through
 it.
 
 When hypothesis is installed (the ``dev`` extra; it is not in the pinned
-runtime deps) a derandomized "ci" profile registers here and activates under
-``CI=true``, so the property tests in ``tests/test_properties.py`` are
-reproducible across CI runs instead of sampling fresh examples per run.
-Local runs keep hypothesis's default randomized profile.
+runtime deps) a derandomized profile is loaded for every run, so the property
+tests in ``tests/test_properties.py`` draw the same examples each time: a run
+cannot pass once and fail the next, and the drawn shapes do not compile anew
+per run.  The example counts stay those of each test's ``@settings``.
 """
 import contextlib
 import os
@@ -23,10 +23,9 @@ import pytest
 with contextlib.suppress(ImportError):
     from hypothesis import settings as _hyp_settings
 
-    _hyp_settings.register_profile("ci", derandomize=True, deadline=None,
+    _hyp_settings.register_profile("derandomized", derandomize=True, deadline=None,
                                    print_blob=True)
-    if os.environ.get("CI"):
-        _hyp_settings.load_profile("ci")
+    _hyp_settings.load_profile("derandomized")
 
 
 def pytest_addoption(parser):

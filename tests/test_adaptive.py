@@ -1,22 +1,15 @@
 """repro.adaptive: telemetry estimation, wire-budget allocation, and the
 end-to-end adaptive train step (subprocess host mesh)."""
-import os
-import pathlib
-import subprocess
-import sys
-import textwrap
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from multidevice import run_with_devices
 
 from repro.adaptive import telemetry as T
 from repro.adaptive.controller import AdaptiveConfig, allocate_bits, budget_bytes
 from repro.core import sample_power_law
 from repro.core.compressors import CompressorConfig, wire_bytes
-
-ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 
 def _tails_for(gammas, *, n=120_000, updates=4, decay=0.9, g_mins=None, rhos=None):
@@ -301,10 +294,4 @@ uniform = (3,) * len(stepper.sizes)
 assert len(stepper._cache) == (2 if plan.bits != uniform else 1), stepper._cache.keys()
 print("OK", losses, plan.bits)
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = str(ROOT / "src")
-    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=1200, env=env)
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
-    assert "OK" in r.stdout
+    assert "OK" in run_with_devices(code, n=4)

@@ -13,15 +13,12 @@ Three contracts:
 import functools
 import importlib.util
 import json
-import os
 import pathlib
-import subprocess
-import sys
-import textwrap
+
+from multidevice import run_with_devices
 
 from repro.analysis import RULES, Finding, ast_lint, suppressed_codes
 
-ROOT = pathlib.Path(__file__).resolve().parents[1]
 CORPUS = pathlib.Path(__file__).resolve().parent / "analysis_corpus"
 
 
@@ -36,15 +33,6 @@ def _traced():
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
-
-
-def _run_analysis(args, timeout=1200):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = str(ROOT / "src")
-    return subprocess.run(
-        [sys.executable, "-m", "repro.analysis", *args],
-        capture_output=True, text=True, timeout=timeout, env=env)
 
 
 # ---------------------------------------------------------------------------
@@ -162,6 +150,19 @@ def test_corpus_scatter_add_flagged():
     assert _codes(f) == ["REPRO104"]
 
 
+def test_corpus_suppressed_trace_site_stays_suppressed():
+    """A jaxpr finding is anchored to the user source line that bound the
+    equation; the allow comment there must silence it.  If anchoring breaks
+    (a swallowed error in the source lookup) the finding comes back."""
+    from repro.analysis import jaxpr_lint
+
+    t = _traced()
+    f = jaxpr_lint.lint_trace(t.scatter_add(), "corpus", compressed=False)
+    assert f[0].where.startswith(str(CORPUS / "traced.py") + ":"), f[0].where
+    assert jaxpr_lint.lint_trace(t.suppressed_scatter_add(), "corpus",
+                                 compressed=False) == []
+
+
 def test_corpus_wire_dtype_flagged():
     from repro.analysis import jaxpr_lint
 
@@ -214,8 +215,7 @@ def test_vmem_real_kernels_within_budget():
 
 def test_cli_quick_real_tree_clean(tmp_path):
     out = tmp_path / "ANALYSIS.json"
-    r = _run_analysis(["--quick", "--json", str(out)])
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
+    run_with_devices(["-m", "repro.analysis", "--quick", "--json", out], n=4)
     rep = json.loads(out.read_text())
     assert rep["version"] == 1 and rep["clean"] is True and rep["findings"] == []
     per = rep["passes"]["jaxpr"]["per_trace"]
@@ -293,10 +293,4 @@ codebook = sum(4.0 * (c.s + 1) for c in cfgs)
 assert got == 8.0 * sum(sizes) + 4 * words - codebook, got
 print("WIRE-CHECK-OK")
 """
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = str(ROOT / "src")
-    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                       capture_output=True, text=True, timeout=1200, env=env)
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
-    assert "WIRE-CHECK-OK" in r.stdout
+    assert "WIRE-CHECK-OK" in run_with_devices(code, n=4)

@@ -1,26 +1,7 @@
 """Distributed-runtime tests.  Multi-device cases run in subprocesses so the
 main pytest process keeps a single CPU device (dry-run contract)."""
-import os
-import pathlib
-import subprocess
-import sys
-import textwrap
-
 import pytest
-
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-
-
-def run_with_devices(code: str, n: int = 8, timeout: int = 1200) -> str:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-    env["PYTHONPATH"] = str(ROOT / "src")
-    r = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=timeout, env=env,
-    )
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
-    return r.stdout
+from multidevice import ROOT, run_with_devices
 
 
 PRELUDE = """
@@ -107,7 +88,7 @@ for sync in ("dsgd", "two_phase", "hierarchical", "faithful"):
 
 
 # The former single-mesh codec spot check (test_sharded_codec_units) is
-# superseded by tests/test_mesh_invariance.py: a parameterized mesh-shape ×
+# superseded by tests/mesh_sweep.py: a parameterized mesh-shape ×
 # sync-method sweep pinning bitwise peer agreement and equality with the
 # single-device reference codec (repro.dist.reference).
 

@@ -1,0 +1,9 @@
+"""Cross-mesh invariance sweep on the 1-D four-peer mesh (see ``tests/mesh_sweep.py``);
+a file of its own so xdist's ``--dist loadfile`` gives it its own worker."""
+import pytest
+from mesh_sweep import run_case
+
+
+@pytest.mark.parametrize("mesh_id", ["data4"])
+def test_sync_matches_reference(mesh_id):
+    run_case(mesh_id)

@@ -6,28 +6,10 @@ Multi-device cases run in subprocesses (fake host devices) so the main
 pytest process keeps a single CPU device, mirroring ``test_dist.py``.
 """
 import json
-import os
-import pathlib
-import subprocess
-import sys
-import textwrap
 
 import numpy as np
 import pytest
-
-ROOT = pathlib.Path(__file__).resolve().parents[1]
-
-
-def run_with_devices(code, n=4, timeout=1500):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-    env["PYTHONPATH"] = str(ROOT / "src")
-    r = subprocess.run(
-        [sys.executable, "-c", textwrap.dedent(code)],
-        capture_output=True, text=True, timeout=timeout, env=env,
-    )
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr[-4000:]}"
-    return r.stdout
+from multidevice import run_with_devices
 
 
 # ---------------------------------------------------------------------------
@@ -99,7 +81,7 @@ assert np.all(np.asarray(cm.predicted_mse) > 0.0)
 for g, w in zip(mean, w_mean):
     np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 print("ALL_OK")
-""")
+""", n=4)
     assert "ALL_OK" in out
 
 
@@ -138,7 +120,7 @@ for sync in ("dsgd", "two_phase", "hierarchical", "faithful"):
     delta = on_ng - off_ng
     assert dict(delta) in ({}, {"psum": 1}), (sync, dict(delta))
 print("ALL_OK")
-""")
+""", n=4)
     assert "ALL_OK" in out
 
 
@@ -187,7 +169,7 @@ for sync, gnorm, comp in cases:
             assert leaf.shape == (n_dp, B), (sync, f, leaf.shape)
     print("OK", sync)
 print("ALL_OK")
-""", timeout=1800)
+""", n=4)
     assert "ALL_OK" in out
 
 
