@@ -156,7 +156,10 @@ def _stats_checks(g, e) -> dict:
     """Fused EF-correct + statistics kernel vs its blockwise oracle.
 
     The corrected bucket, the histogram counts (bins come from the fp32
-    bits) and the max are exact.  The float sums go through ``log`` and
+    bits) and the max are exact.  The counts are also held exactly to the
+    scatter-add fallback (``ref.bucket_stats_scatter``), which shares no
+    accumulation code with the kernel: an order or precision fault in the
+    lane-parallel accumulation that the oracle would repeat shows there.  The float sums go through ``log`` and
     reductions that Mosaic and XLA evaluate differently, so they are held to
     1e-6 of their scale: the total ln-mass for the per-bin ln-sums,
     ``sqrt(n · Σg²)`` (a bound on Σ|g|) for Σg, Σg² itself for Σg².
@@ -167,10 +170,12 @@ def _stats_checks(g, e) -> dict:
 
     c_k, s_k = ops.ef_correct_stats(g, e)
     c_o, tile = ref.ef_correct_stats(g, e)
+    scatter_counts = ref.bucket_stats_scatter(c_o)[0]
     scale_sum = jnp.sqrt(g.size * tile[4, 0])
     return {
         "corrected_mismatches": (jnp.sum(c_k != c_o), 0),
         "count_mismatches": (jnp.sum(s_k.counts != tile[0]), 0),
+        "count_mismatches_vs_scatter": (jnp.sum(s_k.counts != scatter_counts), 0),
         "max_mismatch": (jnp.sum(s_k.g_max != tile[2, 0]), 0),
         "log_sums_diff_over_ln_mass": (
             jnp.max(jnp.abs(s_k.log_sums - tile[1])) / jnp.sum(jnp.abs(tile[1])), 1e-6),

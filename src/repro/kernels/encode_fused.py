@@ -11,11 +11,11 @@ kernels collapse that pipeline to two VMEM passes:
   the full statistics tile both ``core.compressors.plan_from_stats`` and
   ``adaptive.telemetry`` consume: per-bin counts of the ``kernels.stats``
   128-bin log2-spaced |g| histogram, per-bin ln|g| Hill sums, max |g| and
-  the first two moments.  The block statistics and the merge rule are the
-  *same functions* the standalone ``kernels.stats`` kernel uses, so the
-  plan-relevant rows of ``c``'s stats (counts, log-sums, max) are
-  bit-identical to ``bucket_stats_2d(c)``; the moment rows are plain
-  reductions with ulp-level fusion discretion.
+  the first two moments.  The grid step is the *same function* the
+  standalone ``kernels.stats`` kernel runs (``stats._accumulate``: per-bin
+  lane partials in VMEM scratch across the grid, one cross-lane reduction
+  per bin on the last step), applied to the corrected tile, so ``c``'s
+  stats are bit-identical to ``bucket_stats_2d(c)`` in interpret mode.
 
 - :func:`uniform_encode_pack_resid_2d` / :func:`codebook_encode_pack_resid_2d`
   — quantize the corrected bucket, bit-pack the codes into the uint32 wire
@@ -35,8 +35,8 @@ kernels collapse that pipeline to two VMEM passes:
   back to HBM at all.
 
 Tiling matches ``kernels.quantize``: (rows, 128) fp32 blocked
-(BLOCK_ROWS, 128) per grid step; the stats kernel uses the smaller
-``stats.BLOCK_ROWS`` tile of the standalone stats kernel.
+(BLOCK_ROWS, 128) per grid step; the stats kernel uses the
+``stats.BLOCK_ROWS`` tile and accumulators of the standalone stats kernel.
 """
 from __future__ import annotations
 
@@ -72,21 +72,9 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 
-def _ef_correct_stats_kernel(n_ref, g_ref, e_ref, c_ref, out_ref):
-    c = g_ref[...] + e_ref[...]
-    c_ref[...] = c
-    bm = c.shape[0]
-    base = pl.program_id(0) * bm
-    row = jax.lax.broadcasted_iota(jnp.int32, (bm, LANES), 0) + base
-    col = jax.lax.broadcasted_iota(jnp.int32, (bm, LANES), 1)
-    valid = row * LANES + col < n_ref[0]
-    part = _s._block_stats(c, valid)
-
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] = _s._merge(out_ref[...], part)
+def _ef_correct_stats_kernel(n_ref, g_ref, e_ref, c_ref, out_ref, cnt_ref, ls_ref):
+    c_ref[...] = g_ref[...] + e_ref[...]
+    _s._accumulate(n_ref, c_ref, out_ref, cnt_ref, ls_ref)
 
 
 def ef_correct_stats_2d(
@@ -112,6 +100,7 @@ def ef_correct_stats_2d(
             jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
             jax.ShapeDtypeStruct((_s.STATS_ROWS, _s.NUM_BINS), jnp.float32),
         ],
+        scratch_shapes=_s.accumulator_scratch(),
         interpret=interpret,
     )(jnp.asarray([n], jnp.int32), g, e)
 

@@ -239,9 +239,9 @@ def ef_correct_stats(
 
     Returns ``(corrected (n,) fp32, BucketStats)``.  Everything the plan
     consumes (counts, log-sums, max) is bit-identical to
-    ``bucket_stats(g + e)`` — same block statistics and merge as
-    ``kernels.stats`` — without the extra HBM sweep; the EMA moment rows
-    are plain reductions with ulp-level fusion discretion.
+    ``bucket_stats(g + e)`` — the same grid step as ``kernels.stats`` —
+    without the extra HBM sweep; the EMA moment rows are plain reductions
+    with ulp-level fusion discretion.
     """
     interpret = _use_interpret() if interpret is None else interpret
     g2, n = _to_2d(g.astype(jnp.float32))

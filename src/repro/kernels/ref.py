@@ -146,8 +146,8 @@ def codebook_encode_pack_residual(
 def bucket_stats_scatter(g: jax.Array):
     """O(n) scatter-add bucket statistics — the shard_map-safe jnp fallback.
 
-    Counts and max are identical to the fused kernel (integer adds / exact
-    max); the float ln/moment sums may differ in the last bits (reduction
+    Counts and max are identical to the fused kernel (int32 adds, one
+    rounding to fp32 / exact max); the float ln/moment sums may differ in the last bits (reduction
     order), which neither the EMA telemetry nor the histogram plan cares
     about — the bit-exact contract is pinned kernel ↔ :func:`bucket_stats`.
     Returns ``(counts, log_sums, g_max, g_sum, g_sumsq)``.
@@ -158,8 +158,7 @@ def bucket_stats_scatter(g: jax.Array):
     gabs = jnp.abs(flat)
     lnab = jnp.log(jnp.maximum(gabs, 1e-30))
     b = S.bin_index(gabs)
-    # repro: allow REPRO104 (counts sum exact 1.0s — order-free; see above)
-    counts = jnp.zeros((S.NUM_BINS,), jnp.float32).at[b].add(1.0)
+    counts = jnp.zeros((S.NUM_BINS,), jnp.int32).at[b].add(1).astype(jnp.float32)
     # repro: allow REPRO104 (last-bit slack documented in the EMA contract)
     log_sums = jnp.zeros((S.NUM_BINS,), jnp.float32).at[b].add(lnab)
     return counts, log_sums, jnp.max(gabs), jnp.sum(flat), jnp.sum(flat * flat)
@@ -242,11 +241,14 @@ def bucket_stats(g: jax.Array) -> jax.Array:
     """Blockwise jnp oracle for ``stats.bucket_stats_2d``.
 
     Scans the padded (rows, 128) layout in the kernel's BLOCK_ROWS grid
-    order, runs the kernel's own block body (``stats._block_stats``) on each
-    block and folds the partials with its add-or-maximum merge
-    (``stats._merge``) — so kernel and oracle agree bit-for-bit (interpret
-    mode executes identical ops in identical order).  A scan, not an
-    unrolled loop: the program stays one block body at any bucket size.
+    order.  Each block goes through the kernel's own pieces
+    (``stats._block_stats``): per chunk and per bin, lane partials added to
+    the bin's (8, 128) accumulator tile, and the moment tile folded with the
+    add-or-maximum merge (``stats._merge``).  After the last block each
+    bin's tile is reduced across its lanes (``stats._lane_totals``) — so
+    kernel and oracle agree bit-for-bit (interpret mode executes identical
+    ops in identical order).  A scan, not an unrolled loop: the program
+    stays one block body at any bucket size.
     """
     from . import stats as S
 
@@ -256,15 +258,22 @@ def bucket_stats(g: jax.Array) -> jax.Array:
     blocks = -(-rows // S.BLOCK_ROWS)
     m = S.BLOCK_ROWS * S.LANES
     padded = jnp.pad(flat, (0, blocks * m - n)).reshape(blocks, S.BLOCK_ROWS, S.LANES)
-    idx = jnp.arange(m, dtype=jnp.int32).reshape(S.BLOCK_ROWS, S.LANES)
 
     def fold(acc, xs):
-        i, gb = xs
-        return S._merge(acc, S._block_stats(gb, idx + i * m < n)), None
+        tile, counts, log_sums = acc
+        i, x = xs
+        part, counts, log_sums = S._block_stats(
+            x, S._valid(x.shape, i * m, n), counts, log_sums)
+        return (S._merge(tile, part), counts, log_sums), None
 
-    acc0 = jnp.zeros((S.STATS_ROWS, S.NUM_BINS), jnp.float32)
-    acc, _ = jax.lax.scan(fold, acc0, (jnp.arange(blocks, dtype=jnp.int32), padded))
-    return acc
+    acc0 = (jnp.zeros((S.STATS_ROWS, S.NUM_BINS), jnp.float32), *S.accumulators())
+    (tile, counts, log_sums), _ = jax.lax.scan(
+        fold, acc0, (jnp.arange(blocks, dtype=jnp.int32), padded))
+
+    def totals(acc):
+        return S._lane_totals(lambda s: acc[s::S.SUBLANES])
+
+    return jnp.concatenate([totals(counts), totals(log_sums), tile[2:]], axis=0)
 
 
 def orthogonalize(p: jax.Array) -> jax.Array:

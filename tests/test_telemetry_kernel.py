@@ -94,7 +94,26 @@ def test_block_stats_ignore_what_lies_past_the_bucket():
     g = sample_power_law(jax.random.key(4), (S.BLOCK_ROWS, S.LANES), gamma=3.6,
                          g_min=0.01, rho=0.15)
     valid = jnp.arange(g.size).reshape(g.shape) < 1000
-    clean = S._block_stats(jnp.where(valid, g, 0.0), valid)
+    block = jax.jit(S._block_stats)
+    clean = block(jnp.where(valid, g, 0.0), valid, *S.accumulators())
     for junk in (jnp.nan, jnp.inf, -jnp.inf):
-        got = S._block_stats(jnp.where(valid, g, junk), valid)
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(clean))
+        got = block(jnp.where(valid, g, junk), valid, *S.accumulators())
+        for a, b in zip(got, clean):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("n", [300_000, 3 * S.BLOCK_ROWS * S.LANES + 77])
+def test_bucket_stats_counts_over_many_blocks(n):
+    """Several grid blocks ending in a partial one, with one bin holding
+    more than 2^16 elements: the lane-parallel counts, reduced once at the
+    last block, equal ``np.histogram`` exactly, sum to n, and the padding
+    past n lands in no bin."""
+    g = sample_power_law(jax.random.key(5), (n,), gamma=3.6, g_min=0.01, rho=0.15)
+    g = g.at[: 70_000].set(0.75)           # one bin past 2^16 elements
+    got = np.asarray(ops.bucket_stats(g).counts)
+    edges = np.asarray(S.bin_edges(), np.float64)
+    want, _ = np.histogram(np.abs(np.asarray(g, np.float64)),
+                           bins=np.concatenate([edges[:-1], [np.inf]]))
+    np.testing.assert_array_equal(got, want.astype(np.float32))
+    assert got.max() > 2**16
+    assert got.sum() == n
